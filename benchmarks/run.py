@@ -16,6 +16,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON records to PATH")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import kernel_bench, paper_tables, roofline
     from .common import HEADER

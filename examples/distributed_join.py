@@ -15,9 +15,13 @@ import numpy as np
 
 from repro.core import JoinConfig, brute_force_knn, plan_join
 from repro.core.distributed import distributed_knn_join
-from repro.core.jax_compat import make_mesh
 from repro.data import forest_like
 from repro.distributed.fault import GroupExecutor, regroup
+
+
+def make_mesh(shape):
+    return jax.make_mesh(shape, ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def main():
@@ -27,7 +31,7 @@ def main():
     S = forest_like(6000, 8, seed=1)
     cfg = JoinConfig(k=10, n_pivots=64, n_groups=n_dev)
     plan = plan_join(R, S, cfg)
-    mesh = make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,))
     res = distributed_knn_join(R, S, plan, mesh)
     bd, _ = brute_force_knn(R, S, 10)
     assert np.allclose(res.distances, bd, atol=1e-2)
@@ -37,7 +41,7 @@ def main():
     # elastic: re-run on half the devices without re-planning phase 1
     half = n_dev // 2
     plan_h = regroup(plan, half)
-    mesh_h = make_mesh((half,), ("data",))
+    mesh_h = make_mesh((half,))
     res_h = distributed_knn_join(R, S, plan_h, mesh_h)
     assert np.allclose(res_h.distances, bd, atol=1e-2)
     print(f"elastic shrink {n_dev}→{half} devices, still exact ✓")

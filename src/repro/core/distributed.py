@@ -55,7 +55,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..kernels.sorted_merge import merge_sorted_runs, next_pow2, tile_topk
 from .api import JoinPlan
 from .index import QueryPlan, SIndex
-from .jax_compat import pvary, shard_map
 from .metrics import canonical_topk
 from .types import JoinResult, JoinStats
 
@@ -174,8 +173,8 @@ def _reducer_join(r_buf, r_valid, s_buf, s_valid, s_ids, k, tile_s,
     if axis_names:
         # inside shard_map the scan carry must match the tiles' varying
         # manual axes; fresh constants start unvarying
-        init_d = pvary(init_d, axis_names)
-        init_i = pvary(init_i, axis_names)
+        init_d = jax.lax.pcast(init_d, axis_names, to="varying")
+        init_i = jax.lax.pcast(init_i, axis_names, to="varying")
 
     def one_r_tile(_, rt):
         r2 = jnp.sum(rt * rt, axis=-1)
@@ -186,7 +185,8 @@ def _reducer_join(r_buf, r_valid, s_buf, s_valid, s_ids, k, tile_s,
             sv = jax.lax.dynamic_slice_in_dim(sv_pad, t_idx * tile_s, tile_s)
             si = jax.lax.dynamic_slice_in_dim(si_pad, t_idx * tile_s, tile_s)
             d2 = (r2[:, None] + jnp.sum(st * st, axis=-1)[None, :]
-                  - 2.0 * (rt @ st.T))
+                  - 2.0 * jnp.matmul(rt, st.T,
+                                     precision=jax.lax.Precision.HIGHEST))
             d2 = jnp.where(sv[None, :], jnp.maximum(d2, 0.0), jnp.inf)
             td, ti = tile_topk(d2, jnp.broadcast_to(si[None, :], d2.shape),
                                kp)
@@ -278,7 +278,7 @@ class DistributedJoinEngine:
         axes, tile_r, tile_s = self.axes, self.tile_r, self.tile_s
         pspec = P(axes if len(axes) > 1 else axes[0])
 
-        @partial(shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(pspec,) * 6,
                  out_specs=(pspec, pspec, pspec, pspec))
         def job2(r_buf, r_valid, r_id, s_buf, s_valid, s_id):
@@ -483,14 +483,15 @@ def distributed_phase1(
     padded = np.pad(np.asarray(data, np.float32), ((0, pad), (0, 0)))
     kk = 0 if k is None else k
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis), P()),
              out_specs=(P(axis), P(axis), P(), P(), P(), P()),
              check_vma=False)  # all_gather+sort output is replicated in
                                # value; the static VMA check can't see it
     def phase1(x, piv):
         d2 = (jnp.sum(x * x, 1)[:, None] + jnp.sum(piv * piv, 1)[None, :]
-              - 2.0 * (x @ piv.T))
+              - 2.0 * jnp.matmul(x, piv.T,
+                                 precision=jax.lax.Precision.HIGHEST))
         d2 = jnp.maximum(d2, 0.0)
         pid = jnp.argmin(d2, axis=1).astype(jnp.int32)
         dist = jnp.sqrt(jnp.take_along_axis(d2, pid[:, None], 1))[:, 0]

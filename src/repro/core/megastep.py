@@ -113,6 +113,7 @@ def _assign_bounds_schedule(q, n_valid, dead_total, segs, center, *,
         d2 = (jnp.sum(qc * qc, 1)[:, None] + jnp.sum(pc * pc, 1)[None, :]
               - 2.0 * jax.lax.dot_general(
                   qc, pc, (((1,), (1,)), ((), ())),
+                  precision=jax.lax.Precision.HIGHEST,
                   preferred_element_type=jnp.float32))
         d2 = jnp.maximum(d2, 0.0)
         qps.append(jnp.sqrt(d2))
@@ -208,7 +209,8 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
             st = s_tiles[tile_idx] - center[None, None, :]
             al = alive_t[tile_idx]                       # (nr_tiles, bn)
             d2 = (q3n[..., None] + jnp.sum(st * st, -1)[:, None, :]
-                  - 2.0 * jnp.einsum("abd,acd->abc", q3, st))
+                  - 2.0 * jnp.einsum("abd,acd->abc", q3, st,
+                                     precision=jax.lax.Precision.HIGHEST))
             d2 = jnp.maximum(d2, 0.0)
             live = ((j < cnt)[:, None, None]) & (al[:, None, :] > 0.0)
             d2 = jnp.where(live, d2, jnp.inf)
@@ -241,6 +243,7 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
         d2 = (jnp.sum(qcs * qcs, 1)[:, None] + jnp.sum(sc * sc, 1)[None, :]
               - 2.0 * jax.lax.dot_general(
                   qcs, sc, (((1,), (1,)), ((), ())),
+                  precision=jax.lax.Precision.HIGHEST,
                   preferred_element_type=jnp.float32))
         d2 = jnp.where(tiles["alive"][None, :] > 0.0,
                        jnp.maximum(d2, 0.0), jnp.inf)
@@ -324,6 +327,18 @@ def _megastep(q, n_valid, dead_total, segs, tiles, state, *,
              jnp.pad(lo, pad, constant_values=-1)))
         d_can, hi, lo = md[:, :k], mhi[:, :k], mlo[:, :k]
     return d_can, hi, lo
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("k", "bm", "metric", "n_finite_total", "seg_meta",
+                     "primary"))
+def _visit_count(q, n_valid, dead_total, segs, center, **kw):
+    """Scheduled (R tile, S tile) visits of stages 1–3, summed."""
+    import jax.numpy as jnp
+    cnt = _assign_bounds_schedule(q, n_valid, dead_total, segs, center,
+                                  **kw)[-1]
+    return jnp.sum(cnt)
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +449,14 @@ class MegastepEngine:
         # mutually exclusive. Reentrant so an owner already holding it
         # can query.
         self.refresh_lock: threading.RLock = threading.RLock()
+
+    @property
+    def resolved_impl(self) -> str:
+        """The step's top-k implementation: the one forced at
+        construction, else the compiled Pallas kernel on TPU and the
+        dense jnp reference elsewhere."""
+        from repro.kernels import ops
+        return self.impl or ("pallas" if ops.use_pallas() else "ref")
 
     # ---- bucketing
 
@@ -615,13 +638,11 @@ class MegastepEngine:
         ``state`` optionally carries a previous (dists, id_hi, id_lo) run
         for the same query slots; it is dedup-merged on device.
         """
-        from repro.kernels import ops
-
         payload = self._refresh()
         bucket = int(q_dev.shape[0])
         # largest power of two <= tile_r, so pow2 buckets always reshape
         bm = min(bucket, self._bm_cap)
-        impl = self.impl or ("pallas" if ops.use_pallas() else "ref")
+        impl = self.resolved_impl
         # span timing = host launch bracket of the one fused call; the
         # stage instants record the fused pipeline's structure with
         # host-known attrs only — nothing here fetches or blocks on the
@@ -643,6 +664,25 @@ class MegastepEngine:
                 impl=impl)
             sp.set(outcome="launched")
             return out
+
+    def tile_counts(self, queries: np.ndarray) -> tuple[int, int]:
+        """``(visited, total)`` (R tile, S tile) pairs in the schedule
+        the megastep builds for one batch — the paper's pruning count.
+        The fused step keeps its schedule on the device and reports no
+        counts (that would cost a sync per batch), so this runs stages
+        1–3 alone and fetches one scalar."""
+        q = self._validated_queries(queries)
+        payload = self._refresh()
+        qd, nv = self.enqueue(q)
+        bm = min(int(qd.shape[0]), self._bm_cap)
+        visited = _visit_count(
+            qd, nv, payload.dead_total, payload.segs,
+            payload.tiles["center"], k=self.config.k, bm=bm,
+            metric=self.config.metric,
+            n_finite_total=payload.n_finite_total,
+            seg_meta=payload.seg_meta, primary=payload.primary)
+        total = -(-q.shape[0] // bm) * sum(t for _, _, t in payload.seg_meta)
+        return int(visited), total
 
     def _validated_queries(self, queries: np.ndarray):
         q = np.ascontiguousarray(queries, np.float32)

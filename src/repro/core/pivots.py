@@ -20,7 +20,8 @@ def pairwise_sqdist(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Squared L2 distances (na, nb).  ``-2ab`` term hits the MXU on TPU."""
     a2 = jnp.sum(a * a, axis=-1, keepdims=True)       # (na, 1)
     b2 = jnp.sum(b * b, axis=-1, keepdims=True).T      # (1, nb)
-    d2 = a2 + b2 - 2.0 * (a @ b.T)
+    d2 = a2 + b2 - 2.0 * jnp.matmul(a, b.T,
+                                    precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 
@@ -45,7 +46,8 @@ def _random_selection(data, m, *, n_sets, rng):
     c = jnp.asarray(cands)
     n2 = jnp.sum(c * c, axis=-1)                              # (T, m)
     d2 = n2[:, :, None] + n2[:, None, :] \
-        - 2.0 * jnp.einsum("tmd,tnd->tmn", c, c)
+        - 2.0 * jnp.einsum("tmd,tnd->tmn", c, c,
+                           precision=jax.lax.Precision.HIGHEST)
     scores = jnp.sqrt(jnp.maximum(d2, 0.0)).sum(axis=(1, 2))  # (T,)
     return cands[int(np.argmax(np.asarray(scores)))]
 
@@ -82,7 +84,8 @@ def _kmeans_selection(data, m, *, sample, rng, iters: int = 10):
         d2 = pairwise_sqdist(pts, centers)                  # (n, m)
         assign = jnp.argmin(d2, axis=1)
         one_hot = jax.nn.one_hot(assign, m, dtype=pts.dtype)  # (n, m)
-        sums = one_hot.T @ pts                              # (m, dim)
+        sums = jnp.matmul(one_hot.T, pts,                   # (m, dim)
+                          precision=jax.lax.Precision.HIGHEST)
         cnts = one_hot.sum(axis=0)[:, None]                 # (m, 1)
         # empty cluster keeps its previous center
         return jnp.where(cnts > 0, sums / jnp.maximum(cnts, 1.0), centers)

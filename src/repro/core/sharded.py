@@ -8,7 +8,7 @@ are assigned to shards by the §5 geometric grouping
 (`SIndex.shard_packing`), each shard holds only its groups' packed rows
 (+ int8 twins + ε bounds) and their Thm-2 tile stats, and the whole
 assign → θ → schedule → gather-top-k → exact-re-rank body runs SPMD
-inside ``shard_map`` (via `core.jax_compat`):
+inside ``jax.shard_map``:
 
 * **θ is global, schedules are per shard.** Every shard carries the
   replicated pivot geometry and T_S pivot-kNN lists of *all* segments,
@@ -68,7 +68,6 @@ import numpy as np
 
 from repro import obs
 
-from .jax_compat import make_mesh, shard_map
 from .megastep import (JoinHandle, MegastepEngine, _assign_bounds_schedule,
                        _bump_trace, _canonical_runs, _gather_topk_run)
 from .types import JoinConfig, JoinStats
@@ -189,13 +188,13 @@ def _sharded_megastep(q, n_valid, dead_total, segs, tiles, state, *,
     kp = next_pow2(k)
     seg_specs, tile_specs = _mesh_specs(segs, tiles)
 
-    @shard_map(mesh=mesh,
-               in_specs=(P(), P(), P(), seg_specs, tile_specs),
-               # all_gather + tree merge leaves every shard holding the
-               # identical final run — replicated in value, which the
-               # static VMA check can't see (same pattern as
-               # distributed.distributed_phase1)
-               out_specs=(P(), P(), P(), P()), check_vma=False)
+    @jax.shard_map(mesh=mesh,
+                   in_specs=(P(), P(), P(), seg_specs, tile_specs),
+                   # all_gather + tree merge leaves every shard holding
+                   # the identical final run — replicated in value, which
+                   # the static VMA check can't see (same pattern as
+                   # distributed.distributed_phase1)
+                   out_specs=(P(), P(), P(), P()), check_vma=False)
     def body(q, n_valid, dead_total, segs, tiles):
         segs, tiles = _strip_shard(segs, tiles)
         # θ below is computed from the replicated union T_S lists —
@@ -227,7 +226,8 @@ def _sharded_megastep(q, n_valid, dead_total, segs, tiles, state, *,
                 pc = sd["pivots_c"]
                 d2 = (jnp.sum(qcs * qcs, axis=1)[:, None]
                       + jnp.sum(pc * pc, axis=1)[None, :]
-                      - 2.0 * (qcs @ pc.T))
+                      - 2.0 * jnp.matmul(
+                          qcs, pc.T, precision=jax.lax.Precision.HIGHEST))
                 dqp = jnp.sqrt(jnp.maximum(d2, 0.0))
                 lb = jnp.maximum(
                     dqp - sd["upper"][None, :].astype(jnp.float32), 0.0)
@@ -297,7 +297,9 @@ class _ShardedPayloadMixin:
                 f"device(s); for a simulated mesh set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count="
                 f"{n_shards} before importing jax")
-        self.mesh = make_mesh((n_shards,), ("shard",))
+        self.mesh = jax.make_mesh(
+            (n_shards,), ("shard",),
+            axis_types=(jax.sharding.AxisType.Auto,))
         self.n_shards = n_shards
         self._init_health()
 
@@ -477,10 +479,9 @@ class _ShardedPayloadMixin:
         """The lock-free tail of the sharded fp32 call: launch the SPMD
         megastep against an already-refreshed payload. Split out so a
         timeout-bounded attempt thread never re-enters refresh_lock."""
-        from repro.kernels import ops
         bucket = int(q_dev.shape[0])
         bm = min(bucket, self._bm_cap)
-        impl = self.impl or ("pallas" if ops.use_pallas() else "ref")
+        impl = self.resolved_impl
         return _sharded_megastep(
             q_dev, n_valid_dev, payload.dead_total, payload.segs,
             payload.tiles, state, mesh=self.mesh, n_shards=self.n_shards,

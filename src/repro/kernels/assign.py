@@ -37,6 +37,7 @@ def assign_kernel(
           + jnp.sum(p * p, axis=1)[None, :]
           - 2.0 * jax.lax.dot_general(
               x, p, (((1,), (1,)), ((), ())),
+              precision=jax.lax.Precision.HIGHEST,
               preferred_element_type=jnp.float32))
     d2 = jnp.maximum(d2, 0.0)
     gid = j * bp + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)

@@ -50,13 +50,16 @@ __all__ = [
 
 
 def _sq_dists(r_ref, s_ref):
-    """(bm, bn) squared L2 distances between the resident tiles."""
+    """(bm, bn) squared L2 distances between the resident tiles. The
+    contraction is full f32 (HIGHEST): the MXU's default one-pass bf16
+    would make the top-k select the wrong rows."""
     r = r_ref[...].astype(jnp.float32)                    # (bm, d)
     s = s_ref[...].astype(jnp.float32)                    # (bn, d)
     d2 = (jnp.sum(r * r, axis=1, keepdims=True)
           + jnp.sum(s * s, axis=1)[None, :]
           - 2.0 * jax.lax.dot_general(
               r, s, (((1,), (1,)), ((), ())),
+              precision=jax.lax.Precision.HIGHEST,
               preferred_element_type=jnp.float32))
     return jnp.maximum(d2, 0.0)
 
@@ -81,7 +84,7 @@ def distance_topk_kernel(
         scratch_d[...] = jnp.full_like(scratch_d, jnp.inf)
         scratch_i[...] = jnp.full_like(scratch_i, -1)
 
-    visit = mask_ref[0, 0] != 0
+    visit = mask_ref[...][0, 0] != 0
 
     @pl.when(visit)
     def _compute():
@@ -123,6 +126,8 @@ def distance_topk_pallas(
     s_pad = jnp.pad(s, ((0, ns_tiles * bn - n_s), (0, 0)))
     if visit_mask is None:
         visit_mask = jnp.ones((nr_tiles, ns_tiles), jnp.int8)
+    # one (1, 1) block per step must cover the array's last two dims
+    visit_mask = visit_mask.astype(jnp.int32).reshape(nr_tiles, ns_tiles, 1, 1)
 
     kernel = functools.partial(
         distance_topk_kernel, k=k, kp=kp, n_s=n_s, bn=bn, ns_tiles=ns_tiles)
@@ -132,7 +137,7 @@ def distance_topk_pallas(
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((None, None, 1, 1), lambda i, j: (i, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
@@ -272,10 +277,13 @@ def distance_topk_gather_pallas(
     args = [schedule.astype(jnp.int32), counts.astype(jnp.int32),
             r_pad, s_pad]
     if alive is not None:
+        # (ns_tiles, 1, bn) so each block covers the array's last two
+        # dims whole — Mosaic refuses a (1, bn) block of an (ns_tiles, bn)
+        # array (the sublane dim must be a multiple of 8 or the full dim)
         alive_pad = jnp.pad(alive.astype(jnp.float32),
-                            (0, ns_tiles * bn - n_s)).reshape(ns_tiles, bn)
-        in_specs.append(
-            pl.BlockSpec((1, bn), lambda i, j, sched, cnt: (sched[i, j], 0)))
+                            (0, ns_tiles * bn - n_s)).reshape(ns_tiles, 1, bn)
+        in_specs.append(pl.BlockSpec(
+            (None, 1, bn), lambda i, j, sched, cnt: (sched[i, j], 0, 0)))
         args.append(alive_pad)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

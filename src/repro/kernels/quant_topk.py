@@ -132,8 +132,7 @@ def quant_coarse_gather_kernel(
         tile = sched_ref[i, j]
         lb = coarse_lb_tile(
             qi_ref[...], qsc_ref[...][:, 0], qeps_ref[...][:, 0],
-            si_ref[...], ssc_ref[0, 0],
-            seps_ref[...][0].astype(jnp.float32))
+            si_ref[...], ssc_ref[0, 0], seps_ref[...][0])
         gid = tile * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
         # liveness (covers tombstones AND tile padding) + the ε-inflated
         # θ prune (lb ≤ θ keeps every true neighbor: its lb lower-bounds
@@ -199,9 +198,15 @@ def quant_coarse_gather_pallas(
     qsc_p = col(qscale, 1.0)
     qeps_p = col(qeps, 0.0)
     th_p = col(theta, -jnp.inf)
-    ssc2 = sscale.astype(jnp.float32).reshape(ns_tiles, 1)
-    seps2 = seps.reshape(ns_tiles, bn)
-    alive2 = alive.astype(jnp.float32).reshape(ns_tiles, bn)
+    # per-tile operands as (ns_tiles, 1, ·): each scheduled block then
+    # covers the array's last two dims whole, as Mosaic requires. ε is
+    # widened to f32 here (exact from f16): Mosaic cannot load a
+    # one-row block of a 16-bit array.
+    ssc3 = sscale.astype(jnp.float32).reshape(ns_tiles, 1, 1)
+    seps3 = seps.astype(jnp.float32).reshape(ns_tiles, 1, bn)
+    alive3 = alive.astype(jnp.float32).reshape(ns_tiles, 1, bn)
+    tile_spec = lambda w: pl.BlockSpec(                 # noqa: E731
+        (None, 1, w), lambda i, j, sched, cnt: (sched[i, j], 0, 0))
 
     kernel = functools.partial(
         quant_coarse_gather_kernel, mp=mp, bn=bn, max_visits=max_visits)
@@ -214,9 +219,9 @@ def quant_coarse_gather_pallas(
             pl.BlockSpec((bm, 1), lambda i, j, sched, cnt: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i, j, sched, cnt: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j, sched, cnt: (sched[i, j], 0)),
-            pl.BlockSpec((1, 1), lambda i, j, sched, cnt: (sched[i, j], 0)),
-            pl.BlockSpec((1, bn), lambda i, j, sched, cnt: (sched[i, j], 0)),
-            pl.BlockSpec((1, bn), lambda i, j, sched, cnt: (sched[i, j], 0)),
+            tile_spec(1),
+            tile_spec(bn),
+            tile_spec(bn),
         ],
         out_specs=[
             pl.BlockSpec((bm, mp), lambda i, j, sched, cnt: (i, 0)),
@@ -236,5 +241,5 @@ def quant_coarse_gather_pallas(
         ],
         interpret=interpret,
     )(schedule.astype(jnp.int32), counts.astype(jnp.int32),
-      qi_p, qsc_p, qeps_p, th_p, si, ssc2, seps2, alive2)
+      qi_p, qsc_p, qeps_p, th_p, si, ssc3, seps3, alive3)
     return out_lb[:n_r], out_pos[:n_r]

@@ -17,7 +17,7 @@ def distance_topk_ref(r: jnp.ndarray, s: jnp.ndarray, k: int):
     r = r.astype(jnp.float32)
     s = s.astype(jnp.float32)
     d2 = (jnp.sum(r * r, 1)[:, None] + jnp.sum(s * s, 1)[None, :]
-          - 2.0 * (r @ s.T))
+          - 2.0 * jnp.matmul(r, s.T, precision=jax.lax.Precision.HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
     neg, idx = jax.lax.top_k(-d2, k)
     return jnp.sqrt(-neg), idx.astype(jnp.int32)
@@ -51,7 +51,7 @@ def distance_topk_gather_ref(
     if alive is not None:
         mask = mask & (alive.astype(jnp.float32) > 0.0)[None, :]
     d2 = (jnp.sum(r * r, 1)[:, None] + jnp.sum(s * s, 1)[None, :]
-          - 2.0 * (r @ s.T))
+          - 2.0 * jnp.matmul(r, s.T, precision=jax.lax.Precision.HIGHEST))
     d2 = jnp.where(mask, jnp.maximum(d2, 0.0), jnp.inf)
     neg, idx = jax.lax.top_k(-d2, k)
     return jnp.sqrt(-neg), idx.astype(jnp.int32)
@@ -158,7 +158,7 @@ def assign_ref(x: jnp.ndarray, pivots: jnp.ndarray):
     x = x.astype(jnp.float32)
     p = pivots.astype(jnp.float32)
     d2 = (jnp.sum(x * x, 1)[:, None] + jnp.sum(p * p, 1)[None, :]
-          - 2.0 * (x @ p.T))
+          - 2.0 * jnp.matmul(x, p.T, precision=jax.lax.Precision.HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
     pid = jnp.argmin(d2, axis=1).astype(jnp.int32)
     return pid, jnp.sqrt(jnp.take_along_axis(d2, pid[:, None], 1))[:, 0]

@@ -20,7 +20,9 @@ flow — so the same code runs inside a Mosaic kernel body, under
 Compare-exchange uses the XOR-partner formulation: the partner of lane
 ``x`` at distance ``dist`` is ``x ^ dist``, materialized with two lane
 rolls and a select (roll lowers to slice+concatenate, which Mosaic
-supports on the lane dimension).
+supports on the lane dimension). The run reversal a bitonic merge needs
+is the same permutation composed over every bit (``x ^ (n-1)``), so no
+``rev`` primitive — which Mosaic does not lower — appears anywhere.
 
 Id payloads: every primitive accepts the id argument either as a single
 int array or as a **tuple of arrays** permuted in lockstep with the
@@ -63,6 +65,24 @@ def _like(i, parts):
     return parts if isinstance(i, tuple) else parts[0]
 
 
+def _xor_lanes(x, dist: int):
+    """``x`` with lane ``l`` moved to lane ``l ^ dist`` (``dist`` a power
+    of two): two lane rolls and a select."""
+    bitc = (_lane_iota(x.shape, x.ndim) & dist) == 0
+    return jnp.where(bitc, jnp.roll(x, -dist, axis=-1),
+                     jnp.roll(x, dist, axis=-1))
+
+
+def _reverse_lanes(x):
+    """``jnp.flip(x, -1)`` for a pow2 last axis, as log2(n) XOR-partner
+    permutations (``l ^ (n-1)`` is the reversal)."""
+    dist = 1
+    while dist < x.shape[-1]:
+        x = _xor_lanes(x, dist)
+        dist *= 2
+    return x
+
+
 def _cmp_swap(d, i, dist: int, asc):
     """One compare-exchange stage over XOR-partners at ``dist`` lanes.
 
@@ -72,18 +92,14 @@ def _cmp_swap(d, i, dist: int, asc):
     a tuple of id arrays permuted together.
     """
     bitc = (_lane_iota(d.shape, d.ndim) & dist) == 0
-
-    def partner(x):
-        return jnp.where(bitc, jnp.roll(x, -dist, axis=-1),
-                         jnp.roll(x, dist, axis=-1))
-
-    p_d = partner(d)
+    p_d = _xor_lanes(d, dist)
     ids = _as_tuple(i)
-    p_ids = tuple(partner(x) for x in ids)
-    d_gt_p = d > p_d
-    p_gt_d = p_d > d
-    take = jnp.where(asc, jnp.where(bitc, d_gt_p, p_gt_d),
-                     jnp.where(bitc, p_gt_d, d_gt_p))
+    p_ids = tuple(_xor_lanes(x, dist) for x in ids)
+    # lane keeps the min of its pair where its low/high position agrees
+    # with the block's direction. Plain boolean algebra: Mosaic cannot
+    # lower a select between boolean vectors.
+    keep_min = bitc == asc
+    take = (keep_min & (d > p_d)) | (~keep_min & (p_d > d))
     out = tuple(jnp.where(take, p, x) for p, x in zip(p_ids, ids))
     return jnp.where(take, p_d, d), _like(i, out)
 
@@ -129,22 +145,26 @@ def tile_topk(d, i, kp: int):
 def merge_sorted_runs(ad, ai, bd, bi):
     """Merge two ascending runs of equal pow2 length; keep the smallest.
 
-    ``concat(A, reverse(B))`` is bitonic, so log2(2k)+1 compare-exchange
-    stages sort it; the first k lanes are the merged smallest-k run.
-    Ids may be single arrays or matching tuples of arrays.
+    ``concat(A, reverse(B))`` is bitonic; its first half-cleaner stage
+    (lane ``x`` against lane ``x + k``) leaves the smallest k in the low
+    half, still bitonic, and log2(k) more stages sort it. Only that low
+    half is computed: the lane-wise min of ``A`` and ``reverse(B)`` (ties
+    keep A), then the in-half stages. Ids may be single arrays or
+    matching tuples of arrays.
     """
     kp = ad.shape[-1]
     assert kp == bd.shape[-1] and kp & (kp - 1) == 0
-    d = jnp.concatenate([ad, jnp.flip(bd, axis=-1)], axis=-1)
+    rd = _reverse_lanes(bd)
+    take = ad > rd
+    d = jnp.where(take, rd, ad)
     i = _like(ai, tuple(
-        jnp.concatenate([a, jnp.flip(b, axis=-1)], axis=-1)
+        jnp.where(take, _reverse_lanes(b), a)
         for a, b in zip(_as_tuple(ai), _as_tuple(bi))))
-    dist = kp
+    dist = kp // 2
     while dist >= 1:
         d, i = _cmp_swap(d, i, dist, True)
         dist //= 2
-    return d[..., :kp], _like(ai, tuple(
-        x[..., :kp] for x in _as_tuple(i)))
+    return d, i
 
 
 def mask_duplicate_ids(ad, ai, bd, bi):

@@ -34,6 +34,8 @@ def main(argv=None):
                     help="shard_map execution over the host devices")
     ap.add_argument("--verify", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     data = (forest_like(args.n, args.dim) if args.dataset == "forest"
             else osm_like(args.n))
@@ -46,13 +48,14 @@ def main(argv=None):
         if args.distributed:
             import jax
             from repro.core.distributed import distributed_knn_join
-            from repro.core.jax_compat import make_mesh
             n_dev = len(jax.devices())
             cfg = JoinConfig(k=args.k, n_pivots=args.pivots, n_groups=n_dev,
                              pivot_strategy=args.pivot_strategy,
                              grouping=args.grouping)
             plan = plan_join(data, data, cfg)
-            mesh = make_mesh((n_dev,), ("data",))
+            mesh = jax.make_mesh(
+                (n_dev,), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
             res = distributed_knn_join(data, data, plan, mesh)
         else:
             res = knn_join(data, data, config=cfg)

@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.jax_compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,10 +11,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     across the DCI — gradients cross pods once per step)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n: int | None = None, name: str = "data"):
     """Small mesh over the actually-present devices (tests, examples)."""
     n = n or len(jax.devices())
-    return make_mesh((n,), (name,))
+    return jax.make_mesh((n,), (name,),
+                         axis_types=(jax.sharding.AxisType.Auto,))

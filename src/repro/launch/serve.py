@@ -30,6 +30,8 @@ def main(argv=None):
     ap.add_argument("--retrieval", action="store_true")
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
     opts = ModelOptions(dtype=jnp.float32 if args.reduced else jnp.bfloat16,

@@ -38,6 +38,8 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--restart", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
     opts = ModelOptions(dtype=jnp.float32 if args.reduced else jnp.bfloat16,

@@ -83,7 +83,6 @@ import numpy as np
 from repro import obs
 from repro.core.megastep import (MegastepEngine, JoinHandle, _bump_trace,
                                  _assign_bounds_schedule)
-from repro.core.jax_compat import shard_map as shard_map_compat
 from repro.core.metrics import canonical_gathered, canonical_topk
 from repro.core.sharded import (_ShardedPayloadMixin, _mesh_specs,
                                 _strip_shard)
@@ -351,8 +350,7 @@ class QuantMegastepEngine(MegastepEngine):
         payload = self._refresh()
         bucket = int(q_dev.shape[0])
         bm = min(bucket, self._bm_cap)
-        from repro.kernels import ops
-        impl = self.impl or ("pallas" if ops.use_pallas() else "ref")
+        impl = self.resolved_impl
         return payload, dict(
             mp=self.mp, k=self.config.k, bm=bm, bn=self._bn,
             metric=self.config.metric, dim=payload.dim,
@@ -625,9 +623,9 @@ def _sharded_quant_megastep(q, n_valid, dead_total, segs, tiles, *,
 
     kp = next_pow2(k)
 
-    @shard_map_compat(mesh=mesh,
-                      in_specs=(P(), P(), P(), *_mesh_specs(segs, tiles)),
-                      out_specs=(P(), P(), P(), P()), check_vma=False)
+    @jax.shard_map(mesh=mesh,
+                   in_specs=(P(), P(), P(), *_mesh_specs(segs, tiles)),
+                   out_specs=(P(), P(), P(), P()), check_vma=False)
     def body(q, n_valid, dead_total, segs, tiles):
         segs, tiles = _strip_shard(segs, tiles)
         lb, pos = _quant_coarse_body(

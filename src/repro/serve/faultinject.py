@@ -64,7 +64,8 @@ import time
 from typing import Any, Callable, Dict, Optional
 
 __all__ = ["FaultPlan", "InjectedFault", "ShardFault", "ShardFailedError",
-           "fire", "transform_value", "cross", "retry_with_backoff"]
+           "fire", "transform_value", "cross", "is_transient",
+           "retry_with_backoff"]
 
 
 class InjectedFault(RuntimeError):
@@ -152,7 +153,7 @@ class FaultPlan:
         reg.counter("fault_crossings_total", site=site).inc()
         reg.counter("fault_injected_total", site=site).inc()
         obs.event("fault.injected", site=site)
-        raise exc if exc is not None else InjectedFault(site)
+        raise _tagged(exc if exc is not None else InjectedFault(site), site)
 
     def _transform_value(self, site: str, value):
         from repro import obs
@@ -186,7 +187,7 @@ class FaultPlan:
         if exc is not None:
             reg.counter("fault_injected_total", site=site).inc()
             obs.event("fault.injected", site=site)
-            raise exc
+            raise _tagged(exc, site)
         if fn is None:
             return value
         reg.counter("fault_injected_total", site=site).inc()
@@ -209,6 +210,24 @@ class FaultPlan:
 
 
 _PLAN: Optional[FaultPlan] = None
+
+
+def _tagged(exc: BaseException, site: str) -> BaseException:
+    """Mark an exception as raised by an armed site, whatever its type
+    (plans may inject any exception), so :func:`is_transient` knows it
+    stands in for a transient fault."""
+    exc.fault_site = site
+    return exc
+
+
+def is_transient(exc: BaseException) -> bool:
+    """Whether the serving ladder may retry ``exc``: a fault an armed
+    plan injected, or a shard failover (:class:`ShardFailedError`).
+    Anything else — a kernel that does not lower or compile, a bug —
+    is the program's fault: retrying it on the host oracle would serve
+    correct answers while hiding that the device path is broken."""
+    return (isinstance(exc, (InjectedFault, ShardFailedError))
+            or hasattr(exc, "fault_site"))
 
 
 def fire(site: str) -> None:
@@ -242,23 +261,23 @@ def cross(site: str, value=None):
 
 def retry_with_backoff(fn: Callable[[int], Any], *, max_retries: int,
                        base_s: float, cap_s: float,
-                       sleep: Callable[[float], None] = time.sleep,
-                       retriable: tuple = (Exception,)):
+                       sleep: Callable[[float], None] = time.sleep):
     """Capped-exponential-backoff retry driver — the serving-loop
     analogue of ``distributed.fault.GroupExecutor``'s bounded re-issue.
 
-    Calls ``fn(attempt)`` (attempt 0 = first try); on a retriable
-    failure sleeps ``min(base_s * 2**attempt, cap_s)`` and re-calls
-    with the next attempt number — the callee routes later attempts
-    onto a safer path (the host-planned oracle). Raises the last error
-    after ``max_retries`` retries.
+    Calls ``fn(attempt)`` (attempt 0 = first try); on a transient
+    failure (:func:`is_transient`) sleeps ``min(base_s * 2**attempt,
+    cap_s)`` and re-calls with the next attempt number — the callee
+    routes later attempts onto a safer path (the host-planned oracle).
+    Raises the last error after ``max_retries`` retries, and any other
+    error at once.
     """
     attempt = 0
     while True:
         try:
             return fn(attempt)
-        except retriable:
-            if attempt >= max_retries:
+        except Exception as e:
+            if attempt >= max_retries or not is_transient(e):
                 raise
             sleep(min(base_s * (2.0 ** attempt), cap_s))
             attempt += 1

@@ -41,7 +41,10 @@ provides:
   (``StreamJoinEngine.join_batch_host``), which owns no device payload
   and therefore cannot re-hit an upload fault. Deadlines keep being
   enforced across backoff: a request that expires while backing off is
-  shed, not dispatched.
+  shed, not dispatched. Only faults ``faultinject.is_transient``
+  accepts take this ladder; any other error (a device step that does
+  not lower or compile, a bug) fails its tickets and propagates out of
+  ``step`` — the oracle never hides a broken device path.
 * **shard failover, deadline-checked** — a sharded engine that loses a
   shard raises ``ShardFailedError`` *after* updating its serving view;
   the scheduler re-enters the engine rung (the next attempt runs on
@@ -563,9 +566,12 @@ class ServeScheduler:
             obs.event("serve.failover", tickets=tks, shard=e.shard)
             self._execute(live, False)
             return sum(t.n for t in batch)
-        except Exception:    # noqa: BLE001 — transient-fault ladder
+        except Exception as e:    # noqa: BLE001 — transient-fault ladder
             with self._lock:
                 self.stats.join = self.stats.join.merged(js)
+            if not faultinject.is_transient(e):
+                self._fail(live, e)
+                raise
             self._execute(live, False, first_attempt=1)
             return sum(t.n for t in batch)
         with self._lock:
@@ -598,9 +604,12 @@ class ServeScheduler:
             obs.event("serve.failover", tickets=tks, shard=e.shard)
             self._execute(live, False)
             return sum(t.n for t in live)
-        except Exception:    # noqa: BLE001 — transient-fault ladder
+        except Exception as e:    # noqa: BLE001 — transient-fault ladder
             with self._lock:
                 self.stats.join = self.stats.join.merged(js)
+            if not faultinject.is_transient(e):
+                self._fail(live, e)
+                raise
             self._execute(live, False, first_attempt=1)
             return sum(t.n for t in live)
         with self._lock:
@@ -749,23 +758,28 @@ class ServeScheduler:
                 base_s=cfg.backoff_base_s, cap_s=cfg.backoff_cap_s,
                 sleep=self._sleep)
         except Exception as e:   # noqa: BLE001 — overload robustness:
-            # a poisoned batch must not take the scheduler down
-            with self._lock:
-                for t in live:
-                    t.status, t.reason = "failed", f"fault: {e!r}"
-                    t.completed_at = self._clock()
-                    self.stats.n_failed += 1
-            obs.metrics.REGISTRY.counter("serve_failed_total") \
-                .inc(len(live))
-            if obs.enabled():
-                obs.event("serve.failed",
-                          tickets=tuple(t.ticket_id for t in live),
-                          error=type(e).__name__)
+            # a batch poisoned past its retries must not take the
+            # scheduler down; a program error (is_transient) must
+            self._fail(live, e)
+            if not faultinject.is_transient(e):
+                raise
             return
         if out is None:
             return                      # everything expired pre-dispatch
         d, i, rb = out
         self._complete(live, d, i, rb)
+
+    def _fail(self, live: List[Ticket], e: Exception) -> None:
+        with self._lock:
+            for t in live:
+                t.status, t.reason = "failed", f"fault: {e!r}"
+                t.completed_at = self._clock()
+                self.stats.n_failed += 1
+        obs.metrics.REGISTRY.counter("serve_failed_total").inc(len(live))
+        if obs.enabled():
+            obs.event("serve.failed",
+                      tickets=tuple(t.ticket_id for t in live),
+                      error=type(e).__name__)
 
     # ---- background worker ------------------------------------------
 

@@ -7,9 +7,8 @@ the sharded megastep (core.sharded — payload partitioned once, bitwise
 the single-device megastep), and ``reducer="shuffle"`` keeps the
 explicit Theorem-6-routed all_to_all + dense scan mapping.
 
-Mesh construction goes through ``repro.core.jax_compat.make_mesh``: the
-seed failure here was ``jax.sharding.AxisType`` not existing on the
-installed JAX (it appeared after 0.4.x), not device-count flakiness.
+Meshes are built with ``Auto`` axis types, the sharding mode the SPMD
+code is written for (``jax.make_mesh`` defaults to ``Explicit``).
 """
 import json
 import os
@@ -27,7 +26,11 @@ _SCRIPT = textwrap.dedent("""
     import jax
     from repro.core import JoinConfig, brute_force_knn, plan_join
     from repro.core.distributed import build_shuffle_spec, distributed_knn_join
-    from repro.core.jax_compat import make_mesh
+
+    def make_mesh(shape, names):
+        return jax.make_mesh(
+            shape, names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(names))
     from repro.core.megastep import MegastepEngine
     from repro.distributed.fault import regroup
 

@@ -235,6 +235,33 @@ def test_permanent_fault_marks_failed_not_hung():
     assert sched.stats.n_failed == 1 and sched.queued_rows == 0
 
 
+@pytest.mark.parametrize("max_inflight", [1, 2])
+def test_device_step_error_propagates_not_served_by_oracle(
+        monkeypatch, max_inflight):
+    """A device step that fails to lower is a program error, not a
+    transient: it fails its tickets and propagates out of the scheduler
+    instead of being answered by the host-planned oracle."""
+    from repro.core import megastep
+
+    def refuses(*_a, **_kw):
+        raise NotImplementedError("Unimplemented primitive in Pallas TPU "
+                                  "lowering: rev")
+
+    eng, _, _ = _engine()
+    host_calls = []
+    sched = ServeScheduler(
+        eng, config=SchedulerConfig(max_inflight=max_inflight),
+        host_join=lambda q, **kw: host_calls.append(q),
+        sleep=lambda _s: None)
+    monkeypatch.setattr(megastep, "_megastep", refuses)
+    t = sched.submit(_data(4, seed=66))
+    with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+        sched.drain()
+    assert t.status == "failed" and "lowering" in t.reason
+    assert not host_calls
+    assert sched.stats.n_retries == 0 and sched.stats.n_failed == 1
+
+
 def test_deadline_enforced_across_backoff():
     """A request that expires while the batch backs off between retries
     is shed, never re-dispatched — n_expired_dispatched stays 0."""

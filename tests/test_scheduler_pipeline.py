@@ -38,7 +38,9 @@ def test_pipelined_bitwise_matches_sync(quantized):
     for mi in (1, 2):
         sched = ServeScheduler(
             eng, config=SchedulerConfig(batch_rows=16, max_inflight=mi))
-        tickets = [sched.submit(q) for q in qs]
+        # deadlines past the first batch's compile: this test is about
+        # bits, and a cold jit cache must not shed the later requests
+        tickets = [sched.submit(q, deadline_s=60.0) for q in qs]
         sched.drain()
         assert all(t.done and not t.degraded for t in tickets)
         outs.append(tickets)
