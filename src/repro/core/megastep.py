@@ -102,65 +102,70 @@ def _assign_bounds_schedule(q, n_valid, dead_total, segs, center, *,
     import jax.numpy as jnp
 
     b = q.shape[0]
-    valid_q = jnp.arange(b) < n_valid
-    qc = q - center[None, :]
 
     # ---- 1. assignment against every segment's pivots (shared with the
     # schedule bounds: the same (B, M) distance matrix feeds both)
-    qps, homes = [], []
-    for g, (m, kk, _) in enumerate(seg_meta):
-        pc = segs[g]["pivots_c"]
-        d2 = (jnp.sum(qc * qc, 1)[:, None] + jnp.sum(pc * pc, 1)[None, :]
-              - 2.0 * jax.lax.dot_general(
-                  qc, pc, (((1,), (1,)), ((), ())),
-                  precision=jax.lax.Precision.HIGHEST,
-                  preferred_element_type=jnp.float32))
-        d2 = jnp.maximum(d2, 0.0)
-        qps.append(jnp.sqrt(d2))
-        homes.append(jnp.argmin(d2, axis=1).astype(jnp.int32))
+    with jax.named_scope("assign"):
+        valid_q = jnp.arange(b) < n_valid
+        qc = q - center[None, :]
+        qps, homes = [], []
+        for g, (m, kk, _) in enumerate(seg_meta):
+            pc = segs[g]["pivots_c"]
+            d2 = (jnp.sum(qc * qc, 1)[:, None]
+                  + jnp.sum(pc * pc, 1)[None, :]
+                  - 2.0 * jax.lax.dot_general(
+                      qc, pc, (((1,), (1,)), ((), ())),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32))
+            d2 = jnp.maximum(d2, 0.0)
+            qps.append(jnp.sqrt(d2))
+            homes.append(jnp.argmin(d2, axis=1).astype(jnp.int32))
 
-    # sort queries by the primary (largest) segment's home partition so R
-    # tiles are partition-coherent — the layout the tile bounds bite on;
-    # padding rows sort last. Undone on the way out via ``inv``.
-    m_primary = seg_meta[primary][0]
-    sort_key = jnp.where(valid_q, homes[primary], m_primary)
-    perm = jnp.argsort(sort_key, stable=True)
-    inv = jnp.argsort(perm)
-    qs = q[perm]
-    qcs = qc[perm]
-    valid_s = valid_q[perm]
-    qps = [qp[perm] for qp in qps]
-    homes = [h[perm] for h in homes]
+        # sort queries by the primary (largest) segment's home partition
+        # so R tiles are partition-coherent — the layout the tile bounds
+        # bite on; padding rows sort last. Undone on the way out via
+        # ``inv``.
+        m_primary = seg_meta[primary][0]
+        sort_key = jnp.where(valid_q, homes[primary], m_primary)
+        perm = jnp.argsort(sort_key, stable=True)
+        inv = jnp.argsort(perm)
+        qs = q[perm]
+        qcs = qc[perm]
+        valid_s = valid_q[perm]
+        qps = [qp[perm] for qp in qps]
+        homes = [h[perm] for h in homes]
 
     # ---- 2. union θ: k-th (+ dead widening) smallest upper bound over
     # every segment's pivot-kNN candidates (Thm 3 at the query, exact for
     # the union top-k; see module docstring)
-    ubs = [(qps[g][:, :, None] + segs[g]["knn"][None, :, :kk]
-            ).reshape(b, m * kk)
-           for g, (m, kk, _) in enumerate(seg_meta)]
-    ub = jnp.concatenate(ubs, axis=1)
-    c_total = ub.shape[1]
-    # capped order statistic instead of a full sort (XLA sort is the slow
-    # op here): bounds for up to w_cap − k tombstones stay tight, beyond
-    # that θ degrades to +inf (visit everything — still exact; compaction
-    # is overdue anyway at that point)
-    w_cap = min(c_total, max(2 * k, 64))
-    small = -jax.lax.top_k(-ub, w_cap)[0]            # ascending smallest
-    dead = jnp.maximum(dead_total.astype(jnp.int32), 0)
-    j = k - 1 + dead
-    idx = jnp.broadcast_to(jnp.minimum(j, w_cap - 1), (b, 1))
-    th = jnp.take_along_axis(small, idx, axis=1)[:, 0]
-    fits = ((k + dead) <= n_finite_total) & (j < w_cap)
-    th = jnp.where(fits, th, jnp.inf)          # no valid bound: visit all
-    th_q = jnp.where(valid_s, th, -jnp.inf)    # padding: schedule nothing
+    with jax.named_scope("bounds"):
+        ubs = [(qps[g][:, :, None] + segs[g]["knn"][None, :, :kk]
+                ).reshape(b, m * kk)
+               for g, (m, kk, _) in enumerate(seg_meta)]
+        ub = jnp.concatenate(ubs, axis=1)
+        c_total = ub.shape[1]
+        # capped order statistic instead of a full sort (XLA sort is the
+        # slow op here): bounds for up to w_cap − k tombstones stay
+        # tight, beyond that θ degrades to +inf (visit everything — still
+        # exact; compaction is overdue anyway at that point)
+        w_cap = min(c_total, max(2 * k, 64))
+        small = -jax.lax.top_k(-ub, w_cap)[0]        # ascending smallest
+        dead = jnp.maximum(dead_total.astype(jnp.int32), 0)
+        j = k - 1 + dead
+        idx = jnp.broadcast_to(jnp.minimum(j, w_cap - 1), (b, 1))
+        th = jnp.take_along_axis(small, idx, axis=1)[:, 0]
+        fits = ((k + dead) <= n_finite_total) & (j < w_cap)
+        th = jnp.where(fits, th, jnp.inf)      # no valid bound: visit all
+        th_q = jnp.where(valid_s, th, -jnp.inf)  # padding: schedule none
 
     # ---- 3. per-segment visit masks, concatenated + prefix-compacted
-    visits = [visit_mask_jnp(qps[g], homes[g], th_q, valid_s,
-                             segs[g]["pivd"], segs[g]["sd_min"],
-                             segs[g]["sd_max"], segs[g]["present"],
-                             bm=bm, metric=metric)
-              for g in range(len(seg_meta))]
-    sched, cnt = compact_visits_jnp(jnp.concatenate(visits, axis=1))
+    with jax.named_scope("schedule"):
+        visits = [visit_mask_jnp(qps[g], homes[g], th_q, valid_s,
+                                 segs[g]["pivd"], segs[g]["sd_min"],
+                                 segs[g]["sd_max"], segs[g]["present"],
+                                 bm=bm, metric=metric)
+                  for g in range(len(seg_meta))]
+        sched, cnt = compact_visits_jnp(jnp.concatenate(visits, axis=1))
     return qs, qcs, valid_s, perm, inv, th_q, sched, cnt
 
 
@@ -306,26 +311,30 @@ def _megastep(q, n_valid, dead_total, segs, tiles, state, *,
         q, n_valid, dead_total, segs, center, k=k, bm=bm, metric=metric,
         n_finite_total=n_finite_total, seg_meta=seg_meta, primary=primary)
 
-    d_run, pos, valid_sel = _gather_topk_run(
-        qs, qcs, valid_s, sched, cnt, tiles, k=k, bm=bm, bn=bn,
-        metric=metric, dim=dim, impl=impl)
+    with jax.named_scope("gather_topk"):
+        d_run, pos, valid_sel = _gather_topk_run(
+            qs, qcs, valid_s, sched, cnt, tiles, k=k, bm=bm, bn=bn,
+            metric=metric, dim=dim, impl=impl)
 
     # ---- 5. canonical distances + global ids + stable re-sort (the
     # exact re-rank over the kp-run) + optional carried-state merge
-    d_can, hi, lo = _canonical_runs(qs, tiles, pos, valid_sel, metric, k)
-    d_can, hi, lo = d_can[inv], hi[inv], lo[inv]
+    with jax.named_scope("canonical"):
+        d_can, hi, lo = _canonical_runs(qs, tiles, pos, valid_sel, metric,
+                                        k)
+        d_can, hi, lo = d_can[inv], hi[inv], lo[inv]
 
     if state is not None:
-        sd, shi, slo = state
-        pad = ((0, 0), (0, kp - k))
-        md, (mhi, mlo) = merge_sorted_runs_unique(
-            jnp.pad(sd, pad, constant_values=jnp.inf),
-            (jnp.pad(shi, pad, constant_values=-1),
-             jnp.pad(slo, pad, constant_values=-1)),
-            jnp.pad(d_can, pad, constant_values=jnp.inf),
-            (jnp.pad(hi, pad, constant_values=-1),
-             jnp.pad(lo, pad, constant_values=-1)))
-        d_can, hi, lo = md[:, :k], mhi[:, :k], mlo[:, :k]
+        with jax.named_scope("merge"):
+            sd, shi, slo = state
+            pad = ((0, 0), (0, kp - k))
+            md, (mhi, mlo) = merge_sorted_runs_unique(
+                jnp.pad(sd, pad, constant_values=jnp.inf),
+                (jnp.pad(shi, pad, constant_values=-1),
+                 jnp.pad(slo, pad, constant_values=-1)),
+                jnp.pad(d_can, pad, constant_values=jnp.inf),
+                (jnp.pad(hi, pad, constant_values=-1),
+                 jnp.pad(lo, pad, constant_values=-1)))
+            d_can, hi, lo = md[:, :k], mhi[:, :k], mlo[:, :k]
     return d_can, hi, lo
 
 
@@ -352,6 +361,7 @@ class _Payload:
     segs: tuple           # per-segment dicts of jnp arrays
     tiles: dict           # concatenated: s, alive, id_hi, id_lo, center
     dead_total: object    # () int32 device scalar
+    n_dead: int           # the same tombstone count, host-side
     seg_meta: tuple       # static ((M, kk, ns_tiles), ...)
     dim: int
     n_finite_total: int
@@ -510,6 +520,7 @@ class MegastepEngine:
                     tiles=dict(st["tiles_dev"],
                                alive=self._put_alive(alive)),
                     dead_total=self._put_rep(np.int32(tomb.size)),
+                    n_dead=int(tomb.size),
                     seg_meta=st["seg_meta"], dim=st["dim"],
                     n_finite_total=st["n_finite_total"],
                     primary=st["primary"])
@@ -626,9 +637,10 @@ class MegastepEngine:
         import jax.numpy as jnp
         n = q.shape[0]
         bucket = self.bucket_for(n)
-        if bucket != n:
-            q = np.pad(q, ((0, bucket - n), (0, 0)))
-        return jnp.asarray(q), jnp.asarray(np.int32(n))
+        with obs.span("megastep.enqueue", rows=n, bucket=bucket):
+            if bucket != n:
+                q = np.pad(q, ((0, bucket - n), (0, 0)))
+            return jnp.asarray(q), jnp.asarray(np.int32(n))
 
     def join_batch_device(self, q_dev, n_valid_dev, *, state=None):
         """The zero-host-transfer steady-state call: device-padded
@@ -643,17 +655,13 @@ class MegastepEngine:
         # largest power of two <= tile_r, so pow2 buckets always reshape
         bm = min(bucket, self._bm_cap)
         impl = self.resolved_impl
-        # span timing = host launch bracket of the one fused call; the
-        # stage instants record the fused pipeline's structure with
+        # span timing = host launch bracket of the one fused call, with
         # host-known attrs only — nothing here fetches or blocks on the
-        # device (the zero-steady-state-sync invariant)
+        # device (the zero-steady-state-sync invariant); the stages inside
+        # are named scopes of the device program
         with obs.span("megastep.device_step", bucket=bucket, bm=bm,
                       bn=self._bn, k=self.config.k, impl=impl,
                       n_segments=len(payload.seg_meta)) as sp:
-            if obs.enabled():
-                for stage in ("assign", "bounds", "schedule",
-                              "gather_topk", "merge"):
-                    obs.event(f"megastep.{stage}", fused=True)
             out = _megastep(
                 q_dev, n_valid_dev, payload.dead_total, payload.segs,
                 payload.tiles, state,
@@ -698,21 +706,22 @@ class MegastepEngine:
         redeem it with :meth:`finalize`. The serving scheduler uses this
         split to overlap batch N's fetch/split with batch N+1's device
         pass (double-buffered dispatch)."""
-        q = self._validated_queries(queries)
-        n = q.shape[0]
-        if n == 0:
-            return JoinHandle(kind="empty", n=0)
-        payload = self._refresh()
-        if stats is not None:
-            stats.n_r += n
-            stats.n_s = max(stats.n_s, self.index.n_s)
-            stats.n_segments = len(payload.seg_meta)
-            stats.n_tombstones = int(np.asarray(payload.dead_total))
-            stats.pivot_pairs_computed += n * sum(
-                m for m, _, _ in payload.seg_meta)
-        qd, nv = self.enqueue(q)
-        d, hi, lo = self.join_batch_device(qd, nv)
-        return JoinHandle(kind="mega", n=n, dev=(d, hi, lo))
+        with obs.span("megastep.dispatch", rows=len(queries)):
+            q = self._validated_queries(queries)
+            n = q.shape[0]
+            if n == 0:
+                return JoinHandle(kind="empty", n=0)
+            payload = self._refresh()
+            if stats is not None:
+                stats.n_r += n
+                stats.n_s = max(stats.n_s, self.index.n_s)
+                stats.n_segments = len(payload.seg_meta)
+                stats.n_tombstones = payload.n_dead
+                stats.pivot_pairs_computed += n * sum(
+                    m for m, _, _ in payload.seg_meta)
+            qd, nv = self.enqueue(q)
+            d, hi, lo = self.join_batch_device(qd, nv)
+            return JoinHandle(kind="mega", n=n, dev=(d, hi, lo))
 
     def finalize(self, handle: JoinHandle, *,
                  stats: Optional[JoinStats] = None
@@ -728,16 +737,22 @@ class MegastepEngine:
         from repro.serve import faultinject
         # the fetch below is the one boundary that synchronizes anyway —
         # bracketing it costs no extra sync, and its wall time is the
-        # device-step completion time
+        # device-step completion time. Where a tracer reads it, ``.wait``
+        # blocks on the buffers the copies would wait on anyway; ``.copy``
+        # is the transfer and the id assembly
         t0 = time.perf_counter()
         with obs.span("megastep.fetch", rows=handle.n):
             faultinject.fire("megastep.fetch")     # simulated lost fetch
             n = handle.n
             d, hi, lo = handle.dev
-            d = np.asarray(d)[:n]
-            ids = ((np.asarray(hi, np.int64) << 32)
-                   | (np.asarray(lo, np.int64)
-                      & np.int64(0xFFFFFFFF)))[:n]
+            if obs.enabled():
+                with obs.span("megastep.fetch.wait"):
+                    jax.block_until_ready(handle.dev)
+            with obs.span("megastep.fetch.copy"):
+                d = np.asarray(d)[:n]
+                ids = ((np.asarray(hi, np.int64) << 32)
+                       | (np.asarray(lo, np.int64)
+                          & np.int64(0xFFFFFFFF)))[:n]
         obs.metrics.REGISTRY.histogram("megastep_finalize_s") \
             .observe(time.perf_counter() - t0)
         return np.ascontiguousarray(d), np.ascontiguousarray(ids)

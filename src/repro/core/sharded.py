@@ -639,7 +639,7 @@ class ShardedMegastepEngine(_ShardedPayloadMixin, MegastepEngine):
                 stats.n_r += n
                 stats.n_s = max(stats.n_s, self.index.n_s)
                 stats.n_segments = len(payload.seg_meta)
-                stats.n_tombstones = int(np.asarray(payload.dead_total))
+                stats.n_tombstones = payload.n_dead
                 stats.pivot_pairs_computed += n * sum(
                     m for m, _, _ in payload.seg_meta)
             qd, nv = self.enqueue(q)

@@ -152,6 +152,7 @@ def distance_topk_pallas(
             pl_scratch((bm, kp), jnp.int32),
         ],
         interpret=interpret,
+        name="distance_topk",
     )(r_pad, s_pad, visit_mask)
     return out_d[:n_r], out_i[:n_r]
 
@@ -306,6 +307,7 @@ def distance_topk_gather_pallas(
             jax.ShapeDtypeStruct((nr_tiles * bm, k), jnp.int32),
         ],
         interpret=interpret,
+        name="gather_topk",
     )(*args)
     return out_d[:n_r], out_i[:n_r]
 

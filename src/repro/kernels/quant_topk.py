@@ -240,6 +240,7 @@ def quant_coarse_gather_pallas(
             jax.ShapeDtypeStruct((nr_tiles * bm, mp), jnp.int32),
         ],
         interpret=interpret,
+        name="quant_coarse_topk",
     )(schedule.astype(jnp.int32), counts.astype(jnp.int32),
       qi_p, qsc_p, qeps_p, th_p, si, ssc3, seps3, alive3)
     return out_lb[:n_r], out_pos[:n_r]

@@ -5,7 +5,9 @@ Three pieces, stdlib-only (importable before jax, safe from any thread):
 * :mod:`repro.obs.trace` — ring-buffer span tracer, off by default,
   one ``None``-check when disabled. Production code brackets stages
   with ``trace.span(...)`` / stamps instants with ``trace.event(...)``;
-  ``obs.capture()`` scopes a recording.
+  ``obs.capture()`` scopes a recording. ``Tracer(profiler=True)``
+  also writes every span into a running JAX profiler trace, beside
+  the device's ops.
 * :mod:`repro.obs.metrics` — always-on counters / gauges / fixed-bucket
   histograms (p50/p99/p999 without stored samples) published into the
   process-global ``metrics.REGISTRY`` by the scheduler, the engines,

@@ -25,6 +25,15 @@ Design constraints, in order:
   ``capacity``; a forgotten enabled tracer degrades to a sliding
   window, never to unbounded growth.
 
+**Profiler mode.** ``install(Tracer(profiler=True))`` also opens a
+``jax.profiler.TraceAnnotation`` under each span's name, with the
+span's scalar attributes known when it opens (``sp.set`` attributes and
+tuples such as ``tickets`` stay in the ring buffer). While a profiler
+trace runs, every program span then lands on its thread's line of the
+host plane, on the profiler's clock, in the same ``.xplane.pb`` as the
+device's ops. jax is imported when such a tracer is installed, so this
+module stays stdlib-only to import.
+
 Usage::
 
     import repro.obs as obs
@@ -88,20 +97,32 @@ class Span:
                 f"attrs={self.attrs!r})")
 
 
+_SCALARS = (bool, int, float, str)
+
+
 class _SpanCtx:
     """Context manager that opens a :class:`Span` on ``__enter__`` and
-    records it on ``__exit__`` (ring-buffer append, stack pop)."""
+    records it on ``__exit__`` (ring-buffer append, stack pop); in
+    profiler mode it also brackets the span with a profiler
+    annotation."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self._span = Span(name, next(tracer._ids),
                           tracer._stack_top(), 0.0,
                           threading.get_ident(), attrs)
+        self._ann = None
 
     def __enter__(self) -> Span:
         sp = self._span
+        annotate = self._tracer._annotate
+        if annotate is not None:
+            self._ann = annotate(sp.name, **{
+                k: v for k, v in sp.attrs.items()
+                if isinstance(v, _SCALARS)})
+            self._ann.__enter__()
         self._tracer._push(sp)
         sp.t0 = sp.t1 = time.perf_counter()
         return sp
@@ -112,6 +133,8 @@ class _SpanCtx:
         if exc_type is not None and "outcome" not in sp.attrs:
             sp.attrs["outcome"] = f"error:{exc_type.__name__}"
         self._tracer._pop(sp)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -137,15 +160,20 @@ NULL_SPAN = _NullSpan()
 class Tracer:
     """Ring-buffer span recorder. Create one per capture (or one
     long-lived per process) and :func:`install` it; ``capacity`` bounds
-    retained spans (oldest dropped first)."""
+    retained spans (oldest dropped first). ``profiler=True`` forwards
+    every span to the JAX profiler as well (see the module docstring)."""
 
-    def __init__(self, capacity: int = 65536):
+    def __init__(self, capacity: int = 65536, *, profiler: bool = False):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         self.capacity = int(capacity)
+        self.profiler = bool(profiler)
         self._buf: deque = deque(maxlen=self.capacity)
         self._ids = itertools.count(1)
         self._local = threading.local()
+        # jax.profiler.TraceAnnotation once a profiler-mode tracer is
+        # installed; None keeps spans in the ring buffer alone
+        self._annotate = None
 
     # ---- per-thread open-span stack (parent linkage) ----------------
 
@@ -209,6 +237,9 @@ def install(tracer: Optional[Tracer] = None) -> Tracer:
     global _TRACER
     if tracer is None:
         tracer = Tracer()
+    if tracer.profiler and tracer._annotate is None:
+        import jax.profiler
+        tracer._annotate = jax.profiler.TraceAnnotation
     _TRACER = tracer
     return tracer
 

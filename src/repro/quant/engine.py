@@ -431,7 +431,7 @@ class QuantMegastepEngine(MegastepEngine):
             stats.n_r += n
             stats.n_s = max(stats.n_s, self.index.n_s)
             stats.n_segments = len(payload.seg_meta)
-            stats.n_tombstones = int(np.asarray(payload.dead_total))
+            stats.n_tombstones = payload.n_dead
             stats.pivot_pairs_computed += n * sum(
                 m for m, _, _ in payload.seg_meta)
         qd, nv = self.enqueue(q)

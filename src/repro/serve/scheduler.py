@@ -323,48 +323,52 @@ class ServeScheduler:
         q = np.ascontiguousarray(queries, np.float32)
         if q.ndim != 2 or q.shape[0] == 0:
             raise ValueError(f"submit wants (n>0, dim) rows, got {q.shape}")
-        now = self._clock()
-        arr = now if arrival is None else float(arrival)
-        dls = self.config.default_deadline_s if deadline_s is None \
-            else float(deadline_s)
-        t = Ticket(rows=q, n=q.shape[0], ticket_id=next(_TICKET_IDS),
-                   priority=priority, arrival=arr, deadline=arr + dls)
-        n_evicted = 0
-        with self._lock:
-            self.stats.n_submitted += 1
-            self.stats.rows_submitted += t.n
-            cap = self.config.max_queued_rows
-            if self._queued_rows + t.n > cap \
-                    and priority == Priority.INTERACTIVE:
-                # interactive may evict queued bulk (newest first): the
-                # lowest-priority work is shed to make room, explicitly
-                bulk = self._lanes[Priority.BULK]
-                while bulk and self._queued_rows + t.n > cap:
-                    victim = bulk.pop()
-                    self._mark_shed_locked(victim, "overload")
-                    self._drop_rows_locked(victim.n)
-                    n_evicted += 1
-            if self._queued_rows + t.n > cap:
-                t.status, t.reason = "rejected", "queue_full"
-                self.stats.n_rejected += 1
-                self.stats.rows_shed += t.n
-                reg = obs.metrics.REGISTRY
-                reg.counter("serve_submitted_total").inc()
-                reg.counter("serve_rejected_total").inc()
-                obs.event("serve.admission", ticket=t.ticket_id, rows=t.n,
-                          priority=int(priority), outcome="rejected")
-                return t
-            self._lanes[priority].append(t)
-            self._queued_rows += t.n
-            queued = self._queued_rows
-            self._work.notify()
-        reg = obs.metrics.REGISTRY
-        reg.counter("serve_submitted_total").inc()
-        reg.gauge("serve_queued_rows").set(queued)
-        obs.event("serve.admission", ticket=t.ticket_id, rows=t.n,
-                  priority=int(priority), outcome="admitted",
-                  evicted_bulk=n_evicted, queued_rows=queued)
-        return t
+        tid = next(_TICKET_IDS)
+        # the span covers the whole admission, the wait for the lock
+        # included (the consumer thread holds it while forming a batch)
+        with obs.span("serve.admission", ticket=tid, rows=q.shape[0],
+                      priority=int(priority)) as sp:
+            now = self._clock()
+            arr = now if arrival is None else float(arrival)
+            dls = self.config.default_deadline_s if deadline_s is None \
+                else float(deadline_s)
+            t = Ticket(rows=q, n=q.shape[0], ticket_id=tid,
+                       priority=priority, arrival=arr, deadline=arr + dls)
+            n_evicted = 0
+            with self._lock:
+                self.stats.n_submitted += 1
+                self.stats.rows_submitted += t.n
+                cap = self.config.max_queued_rows
+                if self._queued_rows + t.n > cap \
+                        and priority == Priority.INTERACTIVE:
+                    # interactive may evict queued bulk (newest first):
+                    # the lowest-priority work is shed to make room,
+                    # explicitly
+                    bulk = self._lanes[Priority.BULK]
+                    while bulk and self._queued_rows + t.n > cap:
+                        victim = bulk.pop()
+                        self._mark_shed_locked(victim, "overload")
+                        self._drop_rows_locked(victim.n)
+                        n_evicted += 1
+                if self._queued_rows + t.n > cap:
+                    t.status, t.reason = "rejected", "queue_full"
+                    self.stats.n_rejected += 1
+                    self.stats.rows_shed += t.n
+                    reg = obs.metrics.REGISTRY
+                    reg.counter("serve_submitted_total").inc()
+                    reg.counter("serve_rejected_total").inc()
+                    sp.set(outcome="rejected")
+                    return t
+                self._lanes[priority].append(t)
+                self._queued_rows += t.n
+                queued = self._queued_rows
+                self._work.notify()
+            reg = obs.metrics.REGISTRY
+            reg.counter("serve_submitted_total").inc()
+            reg.gauge("serve_queued_rows").set(queued)
+            sp.set(outcome="admitted", evicted_bulk=n_evicted,
+                   queued_rows=queued)
+            return t
 
     def snapshot(self) -> SchedulerStats:
         """Consistent copy of :attr:`stats` taken under the scheduler
@@ -455,15 +459,18 @@ class ServeScheduler:
         window is full — batch N's device pass overlaps batch N+1's
         formation + dispatch. An empty queue drains the window.
         """
+        with obs.span("serve.step"):
+            return self._step()
+
+    def _step(self) -> int:
         now = self._clock()
-        with self._lock:
-            pressure = self._queued_rows
-            batch = self._form_batch_locked(now)
-        if batch and obs.enabled():
-            obs.event("serve.coalesce",
-                      tickets=tuple(t.ticket_id for t in batch),
-                      rows=sum(t.n for t in batch),
-                      queued_rows=pressure)
+        with obs.span("serve.coalesce") as sp:
+            with self._lock:
+                pressure = self._queued_rows
+                batch = self._form_batch_locked(now)
+            if batch and obs.enabled():
+                sp.set(tickets=tuple(t.ticket_id for t in batch),
+                       rows=sum(t.n for t in batch), queued_rows=pressure)
         obs.metrics.REGISTRY.gauge("serve_queued_rows") \
             .set(self._queued_rows)
         degraded = (self.degraded_engine is not None
@@ -620,34 +627,32 @@ class ServeScheduler:
     # ---- synchronous execution with retries -------------------------
 
     def _complete(self, live: List[Ticket], d, i, rb) -> None:
-        done_at = self._clock()
-        lo = 0
-        with self._lock:
+        tks = tuple(t.ticket_id for t in live) if obs.enabled() else ()
+        with obs.span("serve.complete", tickets=tks,
+                      rows=sum(t.n for t in live), degraded=rb is not None):
+            done_at = self._clock()
+            lo = 0
+            with self._lock:
+                for t in live:
+                    t.distances = d[lo:lo + t.n]
+                    t.indices = i[lo:lo + t.n]
+                    t.recall_bound = (rb[lo:lo + t.n] if rb is not None
+                                      else np.ones(t.n, np.float32))
+                    t.degraded = rb is not None
+                    t.status = "done"
+                    t.completed_at = done_at
+                    lo += t.n
+                    self.stats.n_completed += 1
+                    self.stats.rows_completed += t.n
+                    if t.degraded:
+                        self.stats.n_degraded_requests += 1
+            reg = obs.metrics.REGISTRY
+            lat = reg.histogram("serve_latency_s")
+            reg.counter("serve_completed_total").inc(len(live))
+            if rb is not None:
+                reg.counter("serve_degraded_total").inc(len(live))
             for t in live:
-                t.distances = d[lo:lo + t.n]
-                t.indices = i[lo:lo + t.n]
-                t.recall_bound = (rb[lo:lo + t.n] if rb is not None
-                                  else np.ones(t.n, np.float32))
-                t.degraded = rb is not None
-                t.status = "done"
-                t.completed_at = done_at
-                lo += t.n
-                self.stats.n_completed += 1
-                self.stats.rows_completed += t.n
-                if t.degraded:
-                    self.stats.n_degraded_requests += 1
-        reg = obs.metrics.REGISTRY
-        lat = reg.histogram("serve_latency_s")
-        reg.counter("serve_completed_total").inc(len(live))
-        if rb is not None:
-            reg.counter("serve_degraded_total").inc(len(live))
-        for t in live:
-            lat.observe(max(0.0, done_at - t.arrival))
-        if obs.enabled():
-            obs.event("serve.complete",
-                      tickets=tuple(t.ticket_id for t in live),
-                      rows=sum(t.n for t in live),
-                      degraded=rb is not None)
+                lat.observe(max(0.0, done_at - t.arrival))
 
     def _execute(self, batch: List[Ticket], degraded: bool, *,
                  first_attempt: int = 0) -> None:
@@ -791,14 +796,19 @@ class ServeScheduler:
             return self._worker
         self._stop = False
 
+        def idle() -> bool:
+            # _inflight is consumer-thread-only state: reading it here
+            # (the consumer) needs no extra locking
+            return (not self._queued_rows and not self._inflight
+                    and not self._stop)
+
         def loop():
             while True:
                 with self._work:
-                    # _inflight is consumer-thread-only state: reading
-                    # it here (the consumer) needs no extra locking
-                    while not self._queued_rows and not self._inflight \
-                            and not self._stop:
-                        self._work.wait(timeout=0.1)
+                    if idle():
+                        with obs.span("serve.wait"):
+                            while idle():
+                                self._work.wait(timeout=0.1)
                     if self._stop:
                         return
                 self.step()

@@ -418,10 +418,30 @@ def test_traced_megastep_steady_state_stays_transfer_free():
     jax.block_until_ready(me.join_batch_device(qd, nv))
     with obs.capture() as tr:
         with jax.transfer_guard("disallow"):
-            jax.block_until_ready(me.join_batch_device(qd, nv))
+            state = jax.block_until_ready(me.join_batch_device(qd, nv))
     names = [s.name for s in tr.spans()]
     assert "megastep.device_step" in names
-    assert "megastep.gather_topk" in names
+    # the stages are named scopes of the device program, not host
+    # instants: they reach the profiler's op metadata at no host cost
+    assert stage_scopes(me, qd, nv, state) == {
+        "assign", "bounds", "schedule", "gather_topk", "canonical",
+        "merge"}
+
+
+def stage_scopes(me, qd, nv, state):
+    """The megastep's stage scopes found in its lowered program (the
+    schedule-driven variant, which keeps stages 2-3 alive on CPU)."""
+    import re
+
+    from repro.core import megastep as ms
+    p = me._refresh()
+    text = ms._megastep.lower(
+        qd, nv, p.dead_total, p.segs, p.tiles, state, k=me.config.k,
+        bm=min(int(qd.shape[0]), me._bm_cap), bn=me._bn,
+        metric=me.config.metric, dim=p.dim,
+        n_finite_total=p.n_finite_total, seg_meta=p.seg_meta,
+        primary=p.primary, impl="ref_sched").as_text(debug_info=True)
+    return set(re.findall(r"jit\(_megastep\)/([a-z_]+)/", text))
 
 
 def test_faultinject_publishes_crossing_metrics():
@@ -433,3 +453,226 @@ def test_faultinject_publishes_crossing_metrics():
         snap = reg.snapshot()
     assert snap['fault_crossings_total{site="sched.dispatch"}'] >= 2
     assert snap['fault_injected_total{site="sched.dispatch"}'] == 1
+
+
+# --------------------------------------------- profiler sink (tracing)
+
+def _profiled_host_spans(logdir):
+    """Every host event of the trace in ``logdir`` as (line, name,
+    start_ns, end_ns, stats); line = (plane, index of the line)."""
+    import glob
+
+    import jax
+    path = sorted(glob.glob(str(logdir / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                out.append(((plane.name, li), e.name, e.start_ns,
+                            e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def _innermost_parent(ev, events):
+    """Name of the shortest other event on ``ev``'s line that contains
+    it, or None."""
+    line, name, s, e, _ = ev
+    outer = [o for o in events if o[0] == line and o is not ev
+             and o[2] <= s and e <= o[3] and (o[3] - o[2]) > (e - s)]
+    return min(outer, key=lambda o: o[3] - o[2])[1] if outer else None
+
+
+PROGRAM_PARENTS = {
+    "serve.admission": None,
+    "serve.coalesce": "serve.step",
+    "serve.attempt": "serve.step",
+    "megastep.dispatch": "serve.attempt",
+    "megastep.enqueue": "megastep.dispatch",
+    "megastep.device_step": "megastep.dispatch",
+    "megastep.fetch": "serve.attempt",
+    "megastep.fetch.wait": "megastep.fetch",
+    "megastep.fetch.copy": "megastep.fetch",
+    "serve.complete": "serve.step",
+}
+
+
+def test_profiler_mode_puts_program_spans_on_the_host_plane(tmp_path):
+    """With a profiler-mode tracer installed, a scheduler turn over the
+    megastep engine writes its spans into the profiler's trace: on the
+    host plane, nested as the code nests them, with the scalar
+    attributes known when each opened (tuples and late ``set``
+    attributes stay in the ring buffer)."""
+    import jax
+    idx, cfg = _index()
+    eng = StreamJoinEngine(idx, cfg, megastep=True)
+    sched = ServeScheduler(eng, config=SchedulerConfig())
+    q = _data(8, seed=11)
+    sched.join_now(q)                      # warm: compile + payload
+    tr = obs.install(obs.Tracer(profiler=True))
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            t = sched.join_now(q)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.uninstall()
+    assert t.done
+    events = _profiled_host_spans(tmp_path)
+    program = [ev for ev in events if ev[1] in PROGRAM_PARENTS
+               or ev[1] == "serve.step"]
+    by_name = {}
+    for ev in program:
+        by_name.setdefault(ev[1], []).append(ev)
+    assert set(PROGRAM_PARENTS) <= set(by_name)
+    for name, parent in PROGRAM_PARENTS.items():
+        for ev in by_name[name]:
+            assert _innermost_parent(ev, program) == parent, name
+    # scalar attributes known at open are forwarded, nothing else
+    (adm,) = by_name["serve.admission"]
+    assert adm[4]["ticket"] == t.ticket_id
+    assert adm[4]["rows"] == q.shape[0]
+    assert "outcome" not in adm[4]              # set later
+    (step,) = by_name["megastep.device_step"]
+    assert step[4]["impl"] == eng.megastep_engine.resolved_impl
+    assert step[4]["bucket"] == 16
+    (att,) = by_name["serve.attempt"]
+    assert att[4]["rung"] == "engine" and "tickets" not in att[4]
+    # the ring buffer still holds everything explain() reads
+    ring = {s.name: s for s in tr.spans()}
+    assert ring["serve.attempt"].attrs["tickets"] == (t.ticket_id,)
+    assert ring["serve.admission"].attrs["outcome"] == "admitted"
+    names = [n.span.name for r in obs.explain(t, tr.spans())
+             for n in r.walk()]
+    assert names[0] == "serve.admission" and "megastep.fetch.copy" in names
+
+
+def test_profiler_mode_wait_span_on_the_consumer_thread(tmp_path):
+    """``serve_forever``'s condition-variable wait is a span of the
+    consumer thread's line; admissions stay on the caller's line."""
+    import time
+
+    import jax
+    idx, cfg = _index()
+    sched = ServeScheduler(StreamJoinEngine(idx, cfg, megastep=True))
+    q = _data(4, seed=12)
+    sched.join_now(q)
+    obs.install(obs.Tracer(profiler=True))
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sched.serve_forever()
+            time.sleep(0.05)
+            t = sched.submit(q)
+            deadline = time.monotonic() + 30
+            while t.status == "queued" and time.monotonic() < deadline:
+                time.sleep(0.005)
+            sched.shutdown()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.uninstall()
+    assert t.done
+    events = _profiled_host_spans(tmp_path)
+    lines = {name: {ev[0] for ev in events if ev[1] == name}
+             for name in ("serve.wait", "serve.step", "serve.admission")}
+    assert lines["serve.wait"] and lines["serve.step"] \
+        and lines["serve.admission"]
+    assert lines["serve.wait"] == lines["serve.step"]
+    assert not lines["serve.wait"] & lines["serve.admission"]
+
+
+def test_obs_imports_jax_only_when_a_profiler_tracer_installs():
+    import subprocess
+    import sys
+    code = ("import sys; import repro.obs as obs; a = 'jax' in sys.modules;"
+            " obs.install(obs.Tracer()); b = 'jax' in sys.modules;"
+            " obs.install(obs.Tracer(profiler=True));"
+            " print(a, b, 'jax' in sys.modules)")
+    import os
+
+    import repro
+    src = os.path.dirname(os.path.dirname(repro.__file__))
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, check=True, env=env)
+    assert out.stdout.split() == ["False", "False", "True"]
+
+
+def test_untraced_path_never_touches_the_profiler(monkeypatch):
+    """No tracer installed: a dispatch/finalize and a scheduler turn
+    construct no profiler annotation (the off path is one ``None``
+    check per site), and the fetch adds no wait of its own before its
+    copies."""
+    import jax
+
+    class Refuse:
+        def __init__(self, *a, **kw):
+            raise AssertionError("profiler annotation on the off path")
+
+    def refuse_wait(*a, **kw):
+        raise AssertionError("block_until_ready on the off path")
+
+    idx, cfg = _index()
+    eng = StreamJoinEngine(idx, cfg, megastep=True)
+    me = eng.megastep_engine
+    q = _data(16, seed=13)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refuse)
+    monkeypatch.setattr(jax, "block_until_ready", refuse_wait)
+    assert not obs.enabled()
+    d, i = me.finalize(me.dispatch(q, stats=JoinStats()))
+    assert d.shape == (16, cfg.k)
+    t = ServeScheduler(eng).join_now(q)
+    assert t.done
+
+
+@pytest.mark.parametrize("engine", ["fp32", "int8_resident", "int8_host"])
+def test_traced_dispatch_fetches_nothing(engine, monkeypatch):
+    """Once the payload is resident, ``dispatch`` (with stats and a
+    tracer installed) makes no device-to-host transfer: the transfer
+    guard refuses one on a chip; on the CPU, where the guard lets
+    host-backed arrays through, turning any jax array into a host value
+    raises."""
+    import jax
+    from jax._src import array as jarray
+
+    from repro.quant.engine import QuantMegastepEngine
+    from repro.core.megastep import MegastepEngine
+
+    idx, cfg = _index()
+    if engine == "fp32":
+        me = MegastepEngine(idx, cfg)
+    else:
+        me = QuantMegastepEngine(idx, cfg, slack=8,
+                                 resident=engine == "int8_resident")
+    q = _data(16, seed=14)
+    me.finalize(me.dispatch(q, stats=JoinStats()))   # payload + compile
+
+    def refuse(*a, **kw):
+        raise AssertionError("device-to-host fetch inside dispatch")
+
+    def host_only(fn):
+        def wrapped(x, *a, **kw):
+            if isinstance(x, jax.Array):
+                refuse()
+            return fn(x, *a, **kw)
+        return wrapped
+
+    with obs.capture() as tr:
+        with monkeypatch.context() as m:
+            for name in ("asarray", "array", "ascontiguousarray"):
+                m.setattr(np, name, host_only(getattr(np, name)))
+            m.setattr(jarray.ArrayImpl, "__array__", refuse)
+            m.setattr(jarray.ArrayImpl, "_value", property(refuse))
+            with jax.transfer_guard_device_to_host("disallow"):
+                js = JoinStats()
+                handle = me.dispatch(q, stats=js)
+    assert js.n_tombstones == 0 and js.n_r == 16
+    assert "megastep.enqueue" in [s.name for s in tr.spans()]
+    d, i = me.finalize(handle)
+    ref_d, ref_i = me.join_batch(q)
+    np.testing.assert_array_equal(d, ref_d)
+    np.testing.assert_array_equal(i, ref_i)
