@@ -179,8 +179,13 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
     kp ≥ k candidates so the canonical re-rank resolves the rank-k
     boundary with exact distances, not the selection metric's fp noise.
 
-    Returns ``(d_run, pos, valid_sel)``: the ascending selection-metric
-    run, packed-row positions (−1 = empty slot) and the validity mask.
+    Returns ``(d_run, pos, valid_sel, merged)``: the ascending
+    selection-metric run, packed-row positions (−1 = empty slot), the
+    validity mask, and the (nr_tiles,) int32 count of each R tile's
+    visited steps that merged a tile (the kernel's gate,
+    `kernels.distance_topk._merge_tile`; None for the dense "ref", which
+    walks no schedule). Padding rows start their run at −inf, so they
+    never hold the gate open and their entries fail ``valid_sel``.
     """
     import jax.numpy as jnp
 
@@ -194,8 +199,9 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
 
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels.distance_topk import distance_topk_gather_pallas
-        d_run, pos = distance_topk_gather_pallas(
+        d_run, pos, merged = distance_topk_gather_pallas(
             qs, tiles["s"], kp, sched, cnt, alive=tiles["alive"],
+            n_valid=jnp.sum(valid_s, dtype=jnp.int32),
             bm=bm, bn=bn, interpret=impl == "pallas_interpret")
         valid_sel = (pos >= 0) & jnp.isfinite(d_run)
     elif impl == "ref_sched":
@@ -209,7 +215,7 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
         kt = min(kp, bn)
 
         def body(carry, xs):
-            cd, ci = carry
+            cd, ci, merged = carry
             tile_idx, j = xs
             st = s_tiles[tile_idx] - center[None, None, :]
             al = alive_t[tile_idx]                       # (nr_tiles, bn)
@@ -228,11 +234,19 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
                 padc = [(0, 0)] * 2 + [(0, kp - kt)]
                 td = jnp.pad(td, padc, constant_values=jnp.inf)
                 ti = jnp.pad(ti, padc, constant_values=-1)
-            return merge_sorted_runs(cd, ci, td, ti), None
+            # the kernel's gate: a step merges where some candidate is
+            # strictly under its row's running kp-th distance
+            beats = jnp.any(d2 < cd[..., kp - 1:kp], axis=(1, 2))
+            cd, ci = merge_sorted_runs(cd, ci, td, ti)
+            return (cd, ci, merged + beats.astype(jnp.int32)), None
 
-        carry0 = (jnp.full((nr_tiles, bm, kp), jnp.inf, jnp.float32),
-                  jnp.full((nr_tiles, bm, kp), -1, jnp.int32))
-        (cd, ci), _ = jax.lax.scan(
+        # padding rows start at −inf, as in the kernel (`_start_run`)
+        run0 = jnp.where(valid_s, jnp.inf, -jnp.inf).astype(jnp.float32)
+        carry0 = (jnp.broadcast_to(run0.reshape(nr_tiles, bm, 1),
+                                   (nr_tiles, bm, kp)),
+                  jnp.full((nr_tiles, bm, kp), -1, jnp.int32),
+                  jnp.zeros((nr_tiles,), jnp.int32))
+        (cd, ci, merged), _ = jax.lax.scan(
             body, carry0,
             (sched.T, jnp.arange(t_total, dtype=jnp.int32)))
         d_run = cd.reshape(b, kp)
@@ -255,7 +269,8 @@ def _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, *,
         neg, pos = jax.lax.top_k(-d2, kp)
         d_run = -neg
         valid_sel = (pos >= 0) & jnp.isfinite(d_run)
-    return d_run, pos, valid_sel
+        merged = None
+    return d_run, pos, valid_sel, merged
 
 
 def _canonical_runs(qs, tiles, pos, valid_sel, metric: str, take: int):
@@ -312,7 +327,7 @@ def _megastep(q, n_valid, dead_total, segs, tiles, state, *,
         n_finite_total=n_finite_total, seg_meta=seg_meta, primary=primary)
 
     with jax.named_scope("gather_topk"):
-        d_run, pos, valid_sel = _gather_topk_run(
+        d_run, pos, valid_sel, _ = _gather_topk_run(
             qs, qcs, valid_s, sched, cnt, tiles, k=k, bm=bm, bn=bn,
             metric=metric, dim=dim, impl=impl)
 
@@ -348,6 +363,24 @@ def _visit_count(q, n_valid, dead_total, segs, center, **kw):
     cnt = _assign_bounds_schedule(q, n_valid, dead_total, segs, center,
                                   **kw)[-1]
     return jnp.sum(cnt)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("k", "bm", "bn", "metric", "dim", "n_finite_total",
+                     "seg_meta", "primary", "impl"))
+def _gather_count(q, n_valid, dead_total, segs, tiles, *, k: int, bm: int,
+                  bn: int, metric: str, dim: int, impl: str, **kw):
+    """Stages 1–4 of the megastep: ``(merged, visited)`` steps, summed
+    over the batch's R tiles."""
+    import jax.numpy as jnp
+    qs, qcs, valid_s, _, _, _, sched, cnt = _assign_bounds_schedule(
+        q, n_valid, dead_total, segs, tiles["center"], k=k, bm=bm,
+        metric=metric, **kw)
+    merged = _gather_topk_run(qs, qcs, valid_s, sched, cnt, tiles, k=k,
+                              bm=bm, bn=bn, metric=metric, dim=dim,
+                              impl=impl)[-1]
+    return jnp.sum(merged), jnp.sum(cnt)
 
 
 # ---------------------------------------------------------------------------
@@ -691,6 +724,27 @@ class MegastepEngine:
             seg_meta=payload.seg_meta, primary=payload.primary)
         total = -(-q.shape[0] // bm) * sum(t for _, _, t in payload.seg_meta)
         return int(visited), total
+
+    def gather_counts(self, queries: np.ndarray) -> tuple[int, int]:
+        """``(merged, visited)`` (R tile, S tile) steps of the gather
+        top-k for one batch: the visited steps of its schedule, and those
+        in which some row had a candidate strictly under its running
+        kp-th distance, so the kernel sorted and merged the tile (the
+        others leave the run as it was). Like :meth:`tile_counts` it runs
+        a program of its own and fetches two scalars, on demand only.
+        The dense "ref" walks no schedule; it counts with "ref_sched"."""
+        q = self._validated_queries(queries)
+        payload = self._refresh()
+        qd, nv = self.enqueue(q)
+        bm = min(int(qd.shape[0]), self._bm_cap)
+        impl = self.resolved_impl
+        merged, visited = _gather_count(
+            qd, nv, payload.dead_total, payload.segs, payload.tiles,
+            k=self.config.k, bm=bm, bn=self._bn, metric=self.config.metric,
+            dim=payload.dim, n_finite_total=payload.n_finite_total,
+            seg_meta=payload.seg_meta, primary=payload.primary,
+            impl="ref_sched" if impl == "ref" else impl)
+        return int(merged), int(visited)
 
     def _validated_queries(self, queries: np.ndarray):
         q = np.ascontiguousarray(queries, np.float32)

@@ -205,7 +205,7 @@ def _sharded_megastep(q, n_valid, dead_total, segs, tiles, state, *,
                 q, n_valid, dead_total, segs, tiles["center"], k=k, bm=bm,
                 metric=metric, n_finite_total=n_finite_total,
                 seg_meta=seg_meta, primary=primary)
-        d_run, pos, valid_sel = _gather_topk_run(
+        d_run, pos, valid_sel, _ = _gather_topk_run(
             qs, qcs, valid_s, sched, cnt, tiles, k=k, bm=bm, bn=bn,
             metric=metric, dim=dim, impl=impl)
         # keep the full kp run: the cross-shard merge must see every

@@ -73,10 +73,10 @@ def distance_topk(
                 r, s, k, schedule, counts, bm=bm, bn=bn, alive=alive)
         return distance_topk_gather_pallas(
             r, s, k, schedule, counts, alive=alive, bm=bm, bn=bn,
-            interpret=impl == "gather_interpret")
+            interpret=impl == "gather_interpret")[:2]
     return distance_topk_pallas(
         r, s, k, visit_mask=visit_mask, bm=bm, bn=bn,
-        interpret=impl == "interpret")
+        interpret=impl == "interpret")[:2]
 
 
 @functools.partial(jax.jit, static_argnames=("mp", "bm", "bn", "impl"))

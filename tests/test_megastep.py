@@ -238,6 +238,28 @@ def test_megastep_impl_variants_match_host(impl):
     np.testing.assert_array_equal(ids, host.indices)
 
 
+@pytest.mark.parametrize("n_q", [97, 128], ids=["ragged", "full"])
+def test_gather_counts_scan_twin_matches_kernel(n_q):
+    """`gather_counts`: the scan twin and the Pallas kernel body
+    (interpret) count the same merged steps, padding rows of a ragged
+    bucket included; visited is `tile_counts`' and "ref" counts with the
+    scan twin. S is integer rows and their negatives, so the shared
+    center is 0 and both walkers see the same squared distances."""
+    rng = np.random.default_rng(7)
+    half = rng.integers(-20, 21, (600, 4)).astype(np.float32)
+    s = np.concatenate([half, -half])
+    cfg = JoinConfig(k=5, n_pivots=16, n_groups=4, seed=1, tile_r=32,
+                     tile_s=64)
+    index = build_index(s, cfg)
+    q = rng.integers(-20, 21, (n_q, 4)).astype(np.float32)
+    got = {impl: MegastepEngine(index, cfg, impl=impl).gather_counts(q)
+           for impl in ("ref", "ref_sched", "pallas_interpret")}
+    assert got["ref_sched"] == got["pallas_interpret"] == got["ref"]
+    merged, visited = got["ref_sched"]
+    assert visited == MegastepEngine(index, cfg).tile_counts(q)[0]
+    assert 0 < merged < visited
+
+
 def test_buffer_segment_cache_survives_compact_reinsert():
     """Regression: ``compact()`` re-bases ``_next_id`` downward, so a
     post-compact write buffer can reproduce the ephemeral buffer-segment

@@ -49,6 +49,7 @@ def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
 
 
 @pytest.mark.parametrize("alive", [False, True], ids=["host", "megastep"])
@@ -63,13 +64,17 @@ def test_distance_topk_gather_compiles(one_chip, d, k, alive):
         return distance_topk_gather_pallas(r, s, k, sched, cnt, alive=live,
                                            bm=BM, bn=BN)
 
-    _compile(fn, one_chip, *shapes)
+    merged = _compile(fn, one_chip, *shapes).out_info[2]
+    assert (merged.shape, merged.dtype) == ((NR_TILES,), jnp.int32)
 
 
-@pytest.mark.parametrize("d,k", [(128, 10)])
+@pytest.mark.parametrize("d,k", [(10, 10), (128, 10)])
 def test_distance_topk_dense_compiles(one_chip, d, k):
-    _compile(lambda r, s: distance_topk_pallas(r, s, k, bm=BM, bn=BN),
-             one_chip, ((N_Q, d), jnp.float32), ((N_S, d), jnp.float32))
+    compiled = _compile(
+        lambda r, s: distance_topk_pallas(r, s, k, bm=BM, bn=BN),
+        one_chip, ((N_Q, d), jnp.float32), ((N_S, d), jnp.float32))
+    merged = compiled.out_info[2]
+    assert (merged.shape, merged.dtype) == ((NR_TILES,), jnp.int32)
 
 
 @pytest.mark.parametrize("d,mp", [(128, 128)])
